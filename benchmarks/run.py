@@ -514,6 +514,9 @@ def main() -> None:
                          "(the CI step ahead of the blocking perf gate) and "
                          "exit nonzero on mismatch")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.fused_parity_smoke:
         print("fused-vs-lax parity smoke:")

@@ -1,43 +1,7 @@
 """FELARE reproduction package.
 
-Also installs a small compatibility alias: ``jax.shard_map`` graduated out
-of ``jax.experimental`` only in newer JAX releases, while this codebase
-(and its tests) use the top-level spelling. On older JAX we alias the
-experimental implementation so both spellings work everywhere.
-
-JAX itself is optional at import time: the static analyzer's AST layer
-(``repro.analysis``, Layer 1) runs on the JAX-less CI lint runner, so a
-missing JAX must not break ``import repro`` — only the subpackages that
-actually trace (core, scenarios, experiments, ...) require it.
+JAX is optional at import time: the static analyzer's AST layer
+(``repro.analysis``, Layer 1) runs on the JAX-less CI lint runner, so
+``import repro`` must not import JAX — only the subpackages that actually
+trace (core, scenarios, experiments, ...) require it.
 """
-try:
-    import jax as _jax
-except ImportError:  # JAX-less lint runner: Layer 1 analysis only
-    _jax = None
-
-if _jax is not None and not hasattr(_jax, "shard_map"):  # < 0.4.x graduation
-    import functools as _functools
-
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    @_functools.wraps(_experimental_shard_map)
-    def _shard_map(f, **kwargs):
-        # The experimental version's static replication checker rejects
-        # replicated out_specs fed by custom collectives; the graduated
-        # version dropped that check, so disable it for parity.
-        kwargs.setdefault("check_rep", False)
-        return _experimental_shard_map(f, **kwargs)
-
-    _jax.shard_map = _shard_map
-
-if _jax is not None and not hasattr(_jax.lax, "pcast"):
-    # jax.lax.pcast marks values as varying over manual mesh axes for the
-    # graduated shard_map's replication tracking. The experimental shard_map
-    # with check_rep=False has no such tracking, so identity is correct.
-    def _pcast(x, axes=None, *, to=None):
-        del axes, to
-        return x
-
-    _jax.lax.pcast = _pcast
-
-del _jax

@@ -52,6 +52,18 @@ def _select_fns(names, use_pallas: bool, use_pallas_map: bool = False):
     return pols
 
 
+def _resolve_dispatcher(dispatcher, use_pallas_map: bool = False):
+    """Resolve the dispatcher, with the fused balance scan when the map
+    kernels are on (``dispatch.with_pallas_balance``; a no-op for
+    dispatchers that never run the scan)."""
+    from repro.core import dispatch as dispatch_mod
+
+    disp = dispatch_mod.resolve(dispatcher)
+    if use_pallas_map:
+        disp = dispatch_mod.with_pallas_balance(disp)
+    return disp
+
+
 def simulate_sweep(traces: Trace, system: SystemSpec, heuristic_names,
                    *, use_pallas_phase1: bool = False,
                    use_pallas_map: bool = False,
@@ -106,14 +118,11 @@ def simulate_sweep(traces: Trace, system: SystemSpec, heuristic_names,
       With observers: ``(Metrics, aux)`` where ``aux`` maps observer name
       to its pytree with the same (H, B, ...) leading dims.
     """
-    from repro.core import dispatch as dispatch_mod
     from repro.core import faults as faults_mod
     from repro.core import observe
 
     obs = observe.resolve(observers)
-    disp = dispatch_mod.resolve(dispatcher)
-    if use_pallas_map:
-        disp = dispatch_mod.with_pallas_balance(disp)
+    disp = _resolve_dispatcher(dispatcher, use_pallas_map)
     disp_label = (dispatcher if isinstance(dispatcher, str)
                   else getattr(disp, "kind", type(disp).__name__))
     dyn = faults_mod.resolve(dynamics)
@@ -161,10 +170,18 @@ def simulate_sweep(traces: Trace, system: SystemSpec, heuristic_names,
 
         B = traces.arrival.shape[0]
         padded = sharding.pad_batch(traces, mesh.devices.size)
+        # check_vma=False: the engine's while_loop carry starts from
+        # constants (now=0, empty queues) that become varying over the
+        # grid axis after one step, and the loop requires the input and
+        # output carry types to match. Marking the initial carry varying
+        # would thread this mesh's axis name into the engine. Each device
+        # simulates its own slice with no collective, so the check has
+        # nothing to verify here.
         sharded = jax.jit(jax.shard_map(
             run_all, mesh=mesh,
             in_specs=P(sharding.SWEEP_AXIS),
             out_specs=P(None, sharding.SWEEP_AXIS),
+            check_vma=False,
         ))
         out = jax.tree.map(lambda x: x[:, :B], sharded(padded))
     del _TRACE_LOG[:-_TRACE_LOG_MAX]

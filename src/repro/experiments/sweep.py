@@ -307,7 +307,10 @@ def print_summary(result: SweepResult, file=None) -> None:
 
 
 def main(argv=None) -> SweepResult:
+    from repro.compile_cache import enable_compile_cache
+
     spec, args = build_spec(argv)
+    enable_compile_cache()
     n = spec.n_simulations
     system_label = args.system or (
         "scenario fleet" if spec.resolve_scenario().fleet is not None

@@ -11,9 +11,9 @@ priority Phase-II is a two-line lax epilogue over the kernel outputs.
 Tiling mirrors ``kernels/phase1_map``: tasks are tiled ``BLOCK_N`` per
 grid step, the (padded) machine dim stays lane-resident, and the
 (padded) EET table rides along whole so task-type rows are gathered
-in-kernel with an exact one-hot dot (one 1.0 per row — the sum is a
-single product, bit-exact). Padding contracts (see ``ops.py``): padded
-machine lanes read start=BIG / qfree=0 / eet=BIG — byte-identical to
+in-kernel with one select per type row (a copy, bit-exact). Padding
+contracts (see ``ops.py``): padded machine lanes read start=BIG /
+qfree=0 / eet=BIG — byte-identical to
 how the engine's masked site views already present out-of-site machines
 — and padded task rows read pending=0, so neither can nominate, win a
 tie-break, or affect a row min.
@@ -34,6 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BIG = 1e30  # python scalar: jnp constants become captured consts in pallas
 BIG_INT = 1 << 30  # int load pad: above any dead-site penalty + queue load
@@ -48,12 +49,42 @@ DROP_KINDS = ("stale", "stale_hopeless")
 
 
 def _type_rows(ttype, eet):
-    """(bn, Mp) EET row of each task's type, via an exact one-hot dot."""
-    bn = ttype.shape[0]
-    sp = eet.shape[0]
-    onehot = (ttype == jax.lax.broadcasted_iota(
-        jnp.int32, (bn, sp), 1)).astype(jnp.float32)
-    return jnp.dot(onehot, eet, preferred_element_type=jnp.float32)
+    """(bn, Mp) EET row of each task's type, one select per type row.
+
+    A select copies the stored f32 value, so the row is exact on every
+    backend. A one-hot matmul would put the gather on the MXU, whose f32
+    passes decide whether the product comes back exact.
+    """
+    e = jnp.zeros((ttype.shape[0], eet.shape[1]), jnp.float32)
+    for s in range(eet.shape[0]):
+        e = jnp.where(ttype == s, eet[s:s + 1, :], e)
+    return e
+
+
+def min_first(x, axis):
+    """``(min, argmin)`` of ``x`` along ``axis``, keepdims, ties to the
+    lowest index -- the tie-break of ``jnp.argmin`` in the lax path.
+
+    Spelled as a min plus the lowest index that holds it because the
+    compiled TPU kernel's ``jnp.argmin`` does not keep that tie-break.
+    """
+    m = jnp.min(x, axis=axis, keepdims=True)
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    return m, jnp.min(jnp.where(x == m, idx, x.shape[axis]), axis=axis,
+                      keepdims=True)
+
+
+def _f32_to_u32(x):
+    """``x.astype(uint32)`` for 0 <= x < 2**32, via the i32 cast Mosaic has.
+
+    The TPU compiler refuses a direct f32 -> u32 cast. Values at or above
+    2**31 are whole numbers in f32, so subtracting 2**31 is exact and the
+    result equals the lax cast over the whole u32 range.
+    """
+    two31 = 2147483648.0
+    hi = x >= two31
+    low = jnp.where(hi, x - two31, x).astype(jnp.int32).astype(jnp.uint32)
+    return low + jnp.where(hi, jnp.uint32(1 << 31), jnp.uint32(0))
 
 
 def _nominate(kind, *, s, e, d, pend, alive, qfree, pdyn, now, gidx,
@@ -65,7 +96,7 @@ def _nominate(kind, *, s, e, d, pend, alive, qfree, pdyn, now, gidx,
     """
     if kind == "random_hash":
         h = (gidx.astype(jnp.uint32) * jnp.uint32(2654435761)
-             + (now * 1e3).astype(jnp.uint32)) % jnp.uint32(n_machines)
+             + _f32_to_u32(now * 1e3)) % jnp.uint32(n_machines)
         return h.astype(jnp.int32), gidx.astype(jnp.float32), alive
     if kind == "min_energy_feasible":
         feas = (s + e <= d) & pend & qfree
@@ -81,8 +112,7 @@ def _nominate(kind, *, s, e, d, pend, alive, qfree, pdyn, now, gidx,
         score = jnp.where(alive & qfree, e, BIG)
     else:  # pragma: no cover - ops.py validates kinds
         raise ValueError(f"unsupported nominator kind {kind!r}")
-    value = jnp.min(score, axis=1, keepdims=True)
-    best = jnp.argmin(score, axis=1, keepdims=True).astype(jnp.int32)
+    value, best = min_first(score, axis=1)
     return best, value, value < BIG
 
 
@@ -162,13 +192,11 @@ def _map_decide_kernel(now_ref, start_ref, pdyn_ref, qfree_ref, eet_ref,
             (True, hikey_ref, hitask_ref), (False, lokey_ref, lotask_ref)):
         pool = nominee & (suff if pool_suff else ~suff)
         masked = jnp.where(pool, key, BIG)
-        tile_min = jnp.min(masked, axis=0, keepdims=True)       # (1, Mp)
-        tile_task = (i * BLOCK_N
-                     + jnp.argmin(masked, axis=0, keepdims=True)
-                     .astype(jnp.int32))
-        # strict < keeps the earliest tile on ties; within-tile argmin
-        # keeps the lowest row — together the global lowest-index
-        # tie-break of jnp.argmin(axis=0).
+        tile_min, tile_row = min_first(masked, axis=0)          # (1, Mp)
+        tile_task = i * BLOCK_N + tile_row
+        # strict < keeps the earliest tile on ties; within a tile the
+        # lowest row wins — together the global lowest-index tie-break
+        # of jnp.argmin(axis=0).
         better = tile_min < key_ref[...]
         key_ref[...] = jnp.where(better, tile_min, key_ref[...])
         task_ref[...] = jnp.where(better, tile_task, task_ref[...])
@@ -272,12 +300,17 @@ def _balance_kernel(load_ref, new_ref, tgt_ref, home_ref, out_ref, *,
     across the whole admission walk instead of round-tripping through a
     lax.scan carry. Mirrors ``core/dispatch/base.py:sequential_balance``
     step for step (integer arithmetic, argmin lowest-index ties).
+
+    The per-task rows live in SMEM: the walk reads and writes one scalar
+    per step at a dynamic index, which Mosaic only allows there. The
+    argmin is spelled as an int min plus a lowest-lane min, because
+    Mosaic's argmin takes f32 only.
     """
     fp = load_ref.shape[1]
     lanes = jax.lax.broadcasted_iota(jnp.int32, (1, fp), 1)
 
     def body(k, load):
-        best = jnp.argmin(load).astype(jnp.int32)
+        best = jnp.min(jnp.where(load == jnp.min(load), lanes, fp))
         s = jnp.where(tgt_ref[0, k] != 0, best, home_ref[0, k])
         out_ref[0, k] = s
         return load + jnp.where((lanes == s) & (new_ref[0, k] != 0), 1, 0)
@@ -292,7 +325,7 @@ def balance_scan_padded(load0, new, tgt, home, *, n_tasks: int,
     an argmin); task columns beyond ``n_tasks`` are never visited."""
     Fp = load0.shape[0]
     Np = new.shape[0]
-    row = pl.BlockSpec((1, Np), lambda i: (0, 0))
+    row = pl.BlockSpec((1, Np), lambda i: (0, 0), memory_space=pltpu.SMEM)
     return pl.pallas_call(
         functools.partial(_balance_kernel, n_tasks=n_tasks),
         grid=(1,),

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.map_fused.kernel import min_first
+
 BIG = 1e30  # python scalar: jnp constants become captured consts in pallas
 BLOCK_N = 128
 
@@ -34,8 +36,7 @@ def _phase1_kernel(avail_ref, pdyn_ref, qfree_ref, eet_ref, dl_ref,
     feas = (s + e <= d) & pend & qfree        # (bn, Mp)
     ec = pdyn_ref[...] * e                    # Eq. 2 middle row (feasible)
     ec = jnp.where(feas, ec, BIG)
-    bestec_ref[...] = jnp.min(ec, axis=1, keepdims=True)
-    bestm_ref[...] = jnp.argmin(ec, axis=1, keepdims=True).astype(jnp.int32)
+    bestec_ref[...], bestm_ref[...] = min_first(ec, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -345,7 +345,7 @@ def test_jx103_debug_print_flagged(monkeypatch):
                         (("noisy-fixture", noisy_program),))
     findings = jaxpr_audit.EffectsAuditCheck().run(load_config(REPO_ROOT))
     assert [f.rule for f in findings] == ["JX103"]
-    assert "debug_callback" in findings[0].message
+    assert "debug_print" in findings[0].message
 
 
 @pytest.mark.slow

@@ -316,6 +316,22 @@ def test_balance_scan_parity(seed, dims):
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
 
 
+@pytest.mark.parametrize("axis", (0, 1))
+def test_min_first_keeps_lowest_index_on_ties(axis):
+    """The kernels' argmin is a min plus the lowest index that holds it:
+    jnp.argmin's tie-break, which a compiled TPU kernel's own argmin does
+    not keep."""
+    from repro.kernels.map_fused.kernel import min_first
+
+    x = np.random.default_rng(0).integers(0, 3, (128, 128))
+    x = x.astype(np.float32)
+    x[:5] = 7.0
+    m, i = min_first(jnp.asarray(x), axis=axis)
+    np.testing.assert_array_equal(np.asarray(m), x.min(axis, keepdims=True))
+    np.testing.assert_array_equal(
+        np.asarray(i), np.argmin(x, axis=axis).reshape(m.shape))
+
+
 @pytest.mark.parametrize("kind", ("least_queued", "fair_spill",
                                   "health_aware"))
 def test_with_pallas_balance_dispatcher_parity(kind):
