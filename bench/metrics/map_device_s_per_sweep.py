@@ -1,0 +1,9 @@
+"""Device seconds of the ``map`` stage (``engine.map``: the mapping
+policy's decision and its evictions, drops and assignments), in one traced
+unit: the self time of the leaf ops under that scope in the sweep program
+(``bench/stage_trace.py``)."""
+from bench import stage_trace
+
+
+def read(r):
+    return stage_trace.read_stage(r, "map")

@@ -1,71 +1,5 @@
-"""Roofline table from the dry-run artifacts (results/dryrun/cells.jsonl)."""
+"""FLOP and byte model of the fused map-decision kernel."""
 from __future__ import annotations
-
-import json
-import pathlib
-
-CELLS = pathlib.Path("results/dryrun/cells.jsonl")
-
-
-def load(path=CELLS):
-    recs = []
-    if not pathlib.Path(path).exists():
-        return recs
-    for line in pathlib.Path(path).read_text().splitlines():
-        try:
-            recs.append(json.loads(line))
-        except json.JSONDecodeError:
-            pass
-    # last record per cell wins (re-runs supersede)
-    by_key = {}
-    for r in recs:
-        by_key[(r["arch"], r["shape"], r["mesh"])] = r
-    return list(by_key.values())
-
-
-def table(mesh="pod", path=CELLS):
-    rows = []
-    for r in load(path):
-        if r["mesh"] != mesh:
-            continue
-        if r["status"] == "skip":
-            rows.append({"arch": r["arch"], "shape": r["shape"],
-                         "status": "SKIP", "note": r["reason"][:40]})
-            continue
-        if r["status"] != "ok":
-            rows.append({"arch": r["arch"], "shape": r["shape"],
-                         "status": "FAIL", "note": r.get("error", "")[:40]})
-            continue
-        ro = r["roofline"]
-        rows.append({
-            "arch": r["arch"], "shape": r["shape"], "status": "ok",
-            "t_comp_ms": round(ro["t_comp_s"] * 1e3, 2),
-            "t_mem_ms": round(ro["t_mem_s"] * 1e3, 2),
-            "t_coll_ms": round(ro["t_coll_s"] * 1e3, 2),
-            "dominant": ro["dominant"],
-            "useful_frac": round(ro["useful_frac"], 3),
-            "mfu": round(ro["mfu"], 4),
-        })
-    return rows
-
-
-def summary(path=CELLS):
-    recs = load(path)
-    ok = sum(1 for r in recs if r["status"] == "ok")
-    skip = sum(1 for r in recs if r["status"] == "skip")
-    fail = sum(1 for r in recs if r["status"] == "fail")
-    return {"fig": "roofline", "cells_ok": ok, "cells_skip": skip,
-            "cells_fail": fail, "pass": fail == 0 and ok > 0}
-
-
-def main(full=False):
-    # prefer the optimized sweep when present; fall back to the baseline
-    final = pathlib.Path("results/dryrun_final/cells.jsonl")
-    path = final if final.exists() else CELLS
-    rows = table("pod", path)
-    derived = summary(path)
-    derived["source"] = str(path)
-    return rows, derived
 
 
 def map_stage(full=False):

@@ -533,7 +533,6 @@ def main() -> None:
 
     benches = dict(paper_figures.ALL)
     benches.update(ablations.ALL)
-    benches["roofline_table"] = roofline_report.main
     benches["roofline_map_stage"] = roofline_report.map_stage
 
     print("name,us_per_call,derived")

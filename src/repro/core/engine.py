@@ -932,9 +932,9 @@ def make_simulator(select_fn: Callable, sysarr: SystemArrays, *,
         def cond(est: EngineState):
             st, aux = est
             halted = _halt(st, aux) if gaters else None
-            return (jnp.isfinite(_next_event_time(st, trace, halted,
-                                                  wake_ts))
-                    & (st.steps < steps_cap))
+            with jax.named_scope("engine.next_event"):
+                t = _next_event_time(st, trace, halted, wake_ts)
+            return jnp.isfinite(t) & (st.steps < steps_cap)
 
         def notify(stage, aux, st):
             return {
@@ -945,23 +945,30 @@ def make_simulator(select_fn: Callable, sysarr: SystemArrays, *,
         def body(est: EngineState):
             st, aux = est
             halted = _halt(st, aux) if gaters else None
-            t = _next_event_time(st, trace, halted, wake_ts)
-            st = st._replace(now=jnp.maximum(t, st.now))
-            st = _stage_finalize(st, trace, sysarr)
+            with jax.named_scope("engine.next_event"):
+                t = _next_event_time(st, trace, halted, wake_ts)
+                st = st._replace(now=jnp.maximum(t, st.now))
+            with jax.named_scope("engine.finalize"):
+                st = _stage_finalize(st, trace, sysarr)
             aux = notify("finalize", aux, st)
-            st = _stage_admit(st, trace, halted)
+            with jax.named_scope("engine.admit"):
+                st = _stage_admit(st, trace, halted)
             aux = notify("admit", aux, st)
             if health:
-                st = _stage_faults(st, trace, sysarr, dynamics, horizon, S,
-                                   backup_k, sites_np, n_sites)
+                with jax.named_scope("engine.faults"):
+                    st = _stage_faults(st, trace, sysarr, dynamics, horizon,
+                                       S, backup_k, sites_np, n_sites)
                 aux = notify("faults", aux, st)
-            st = _stage_dispatch(st, trace, sysarr, dispatcher, sites_np,
-                                 n_sites, fairness_factor, health, net)
+            with jax.named_scope("engine.dispatch"):
+                st = _stage_dispatch(st, trace, sysarr, dispatcher, sites_np,
+                                     n_sites, fairness_factor, health, net)
             aux = notify("dispatch", aux, st)
-            st = _stage_map(st, trace, sysarr, select_fn, fairness_factor, S,
-                            site_members, sites_np, health, backup_k)
+            with jax.named_scope("engine.map"):
+                st = _stage_map(st, trace, sysarr, select_fn, fairness_factor,
+                                S, site_members, sites_np, health, backup_k)
             aux = notify("map", aux, st)
-            st = _stage_start(st, trace, sysarr, health)
+            with jax.named_scope("engine.start"):
+                st = _stage_start(st, trace, sysarr, health)
             aux = notify("start", aux, st)
             return EngineState(st._replace(steps=st.steps + 1), aux)
 
@@ -977,6 +984,7 @@ def make_simulator(select_fn: Callable, sysarr: SystemArrays, *,
             energy_wasted=st.e_wasted,
             energy_idle=e_idle,
             makespan=makespan,
+            steps=st.steps,
         )
         if not observers:
             return metrics

@@ -254,7 +254,13 @@ class EngineState(NamedTuple):
 
 
 class Metrics(NamedTuple):
-    """Aggregate results of one simulated trace."""
+    """Aggregate results of one simulated trace.
+
+    ``steps`` is what the run cost rather than what it simulated: the
+    event loop's iterations for this trace (int32). Under ``vmap`` the
+    loop runs until its slowest trace ends, so a batch's iterations are
+    the max over its lanes, and the rest is lanes left idle.
+    """
 
     completed_by_type: jnp.ndarray  # (S,)
     missed_by_type: jnp.ndarray     # (S,)
@@ -264,6 +270,7 @@ class Metrics(NamedTuple):
     energy_wasted: jnp.ndarray      # () dynamic energy spent on missed tasks
     energy_idle: jnp.ndarray        # () idle energy over the makespan
     makespan: jnp.ndarray           # () time of last event
+    steps: jnp.ndarray              # () int32 event-loop iterations
 
     @property
     def completion_rate_by_type(self):

@@ -64,7 +64,10 @@ class SweepResult:
       heuristics: H heuristic names (axis 0 of every array below).
       rates: R arrival rates (axis 1).
       metrics: raw Metrics pytree; count leaves are (H, R, K, S) int arrays,
-        energy/makespan leaves are (H, R, K) floats.
+        energy/makespan leaves are (H, R, K) floats, and ``steps`` (the
+        event loop's iterations per trace) is (H, R, K) int32. The CSV and
+        JSON summaries leave ``steps`` out: it is what a trace cost, not
+        what it simulated.
       aux: observer outputs keyed by observer name (empty dict when the
         spec attached none); every leaf leads with the same (H, R, K)
         batch dims — e.g. the ``timeline`` observer's ``e_dyn`` series is

@@ -33,6 +33,13 @@ from repro.experiments.spec import SweepSpec
 _TRACE_LOG: list = []
 _TRACE_LOG_MAX = 256
 
+# Host spans on the profiler's clock (a no-op unless a profiler runs):
+# ``sweep.build`` (policy resolution and simulator construction, up to the
+# jit call), ``sweep.trace.<NAME>`` (one per heuristic body JAX traces),
+# and in ``run_sweep`` ``sweep.stack`` and ``sweep.reduce``. Each
+# heuristic's device loop runs under ``jax.named_scope("sweep.<NAME>")``.
+_annotate = jax.profiler.TraceAnnotation
+
 
 def _select_fns(names, use_pallas: bool, use_pallas_map: bool = False):
     """Resolve policy names through the registry, with the Pallas toggles.
@@ -119,33 +126,38 @@ def simulate_sweep(traces: Trace, system: SystemSpec, heuristic_names,
       to its pytree with the same (H, B, ...) leading dims.
     """
     from repro.core import faults as faults_mod
+    from repro.core import network as network_mod
     from repro.core import observe
 
-    obs = observe.resolve(observers)
-    disp = _resolve_dispatcher(dispatcher, use_pallas_map)
-    disp_label = (dispatcher if isinstance(dispatcher, str)
-                  else getattr(disp, "kind", type(disp).__name__))
-    dyn = faults_mod.resolve(dynamics)
-    dyn_label = (dynamics if isinstance(dynamics, str)
-                 else getattr(dyn, "kind", type(dyn).__name__))
-    from repro.core import network as network_mod
+    with _annotate("sweep.build"):
+        obs = observe.resolve(observers)
+        disp = _resolve_dispatcher(dispatcher, use_pallas_map)
+        disp_label = (dispatcher if isinstance(dispatcher, str)
+                      else getattr(disp, "kind", type(disp).__name__))
+        dyn = faults_mod.resolve(dynamics)
+        dyn_label = (dynamics if isinstance(dynamics, str)
+                     else getattr(dyn, "kind", type(dyn).__name__))
+        net = network_mod.resolve(network)
+        net_label = (network if isinstance(network, str)
+                     else getattr(net, "kind", type(net).__name__))
+        sysarr = system.as_jax()
+        sims = [
+            engine.make_simulator(
+                fn, sysarr, queue_size=system.queue_size,
+                fairness_factor=float(system.fairness_factor),
+                max_steps=max_steps, observers=obs,
+                dispatcher=disp, site_of_machine=system.sites,
+                dynamics=dyn, network=net,
+                tier_of_site=getattr(system, "tiers", None),
+            )
+            for fn in _select_fns(heuristic_names, use_pallas_phase1,
+                                  use_pallas_map)
+        ]
+        mesh = None
+        if shard:
+            from repro.distributed import sharding
 
-    net = network_mod.resolve(network)
-    net_label = (network if isinstance(network, str)
-                 else getattr(net, "kind", type(net).__name__))
-    sysarr = system.as_jax()
-    sims = [
-        engine.make_simulator(
-            fn, sysarr, queue_size=system.queue_size,
-            fairness_factor=float(system.fairness_factor),
-            max_steps=max_steps, observers=obs,
-            dispatcher=disp, site_of_machine=system.sites,
-            dynamics=dyn, network=net,
-            tier_of_site=getattr(system, "tiers", None),
-        )
-        for fn in _select_fns(heuristic_names, use_pallas_phase1,
-                              use_pallas_map)
-    ]
+            mesh = sharding.sweep_mesh()
 
     def run_all(tr):
         per_h = []
@@ -153,20 +165,15 @@ def simulate_sweep(traces: Trace, system: SystemSpec, heuristic_names,
             _TRACE_LOG.append(
                 (name, trace_label, disp_label, dyn_label,
                  net_label))  # trace-time
-            per_h.append(jax.vmap(sim)(tr))
+            with (_annotate(f"sweep.trace.{name}"),
+                  jax.named_scope(f"sweep.{name}")):
+                per_h.append(jax.vmap(sim)(tr))
         return jax.tree.map(lambda *xs: jnp.stack(xs), *per_h)
 
-    mesh = None
-    if shard:
-        from repro.distributed import sharding
-
-        mesh = sharding.sweep_mesh()
     if mesh is None:
         out = jax.jit(run_all)(traces)
     else:
         from jax.sharding import PartitionSpec as P
-
-        from repro.distributed import sharding
 
         B = traces.arrival.shape[0]
         padded = sharding.pad_batch(traces, mesh.devices.size)
@@ -207,15 +214,16 @@ def run_sweep(spec: SweepSpec, *, shard: bool = False) -> SweepResult:
     """
     system = spec.resolve_system()
     scenario = spec.resolve_scenario()
-    key = jax.random.PRNGKey(spec.seed)
-    stacked = scenario.stack(
-        key, spec.rates, spec.reps, spec.n_tasks, system.eet,
-        cv_run=spec.cv_run,
-    )
     R, K = len(spec.rates), spec.reps
-    flat = jax.tree.map(
-        lambda x: x.reshape((R * K,) + x.shape[2:]), stacked
-    )
+    with _annotate("sweep.stack"):
+        key = jax.random.PRNGKey(spec.seed)
+        stacked = scenario.stack(
+            key, spec.rates, spec.reps, spec.n_tasks, system.eet,
+            cv_run=spec.cv_run,
+        )
+        flat = jax.tree.map(
+            lambda x: x.reshape((R * K,) + x.shape[2:]), stacked
+        )
     label = (spec.scenario if isinstance(spec.scenario, str)
              else "<custom scenario>")
     observers = spec.resolve_observers()
@@ -229,6 +237,7 @@ def run_sweep(spec: SweepSpec, *, shard: bool = False) -> SweepResult:
     metrics, aux = out if observers else (out, {})
     H = len(spec.heuristics)
     unflatten = lambda x: x.reshape((H, R, K) + x.shape[2:])
-    metrics = jax.tree.map(unflatten, metrics)
-    aux = jax.tree.map(unflatten, aux)
-    return SweepResult.from_metrics(spec, system, metrics, aux=aux)
+    with _annotate("sweep.reduce"):
+        metrics = jax.tree.map(unflatten, metrics)
+        aux = jax.tree.map(unflatten, aux)
+        return SweepResult.from_metrics(spec, system, metrics, aux=aux)

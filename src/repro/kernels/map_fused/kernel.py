@@ -230,6 +230,7 @@ def map_decide_padded(now, start, p_dyn, qfree, eet, deadline, pending,
             task_col, task_col, task_col, task_col,
         ],
         out_specs=[task_col, acc_row, acc_row, acc_row, acc_row],
+        name="map_decide",
         out_shape=[
             jax.ShapeDtypeStruct((N, 1), jnp.int32),
             jax.ShapeDtypeStruct((1, Mp), jnp.float32),
@@ -284,6 +285,7 @@ def evict_stats_padded(start, qfree, eet, deadline, pending, task_type, *,
             jax.ShapeDtypeStruct((N, 1), jnp.int32),
             jax.ShapeDtypeStruct((N, 1), jnp.float32),
         ],
+        name="evict_stats",
         interpret=interpret,
     )(
         start.reshape(1, Mp), qfree.reshape(1, Mp), eet,
@@ -332,6 +334,7 @@ def balance_scan_padded(load0, new, tgt, home, *, n_tasks: int,
         in_specs=[pl.BlockSpec((1, Fp), lambda i: (0, 0)), row, row, row],
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((1, Np), jnp.int32),
+        name="balance_scan",
         interpret=interpret,
     )(
         load0.reshape(1, Fp), new.reshape(1, Np), tgt.reshape(1, Np),

@@ -64,6 +64,7 @@ def phase1_map_padded(avail, p_dyn, qfree, eet_rows, deadline, pending,
             jax.ShapeDtypeStruct((N, 1), jnp.int32),
             jax.ShapeDtypeStruct((N, 1), jnp.float32),
         ],
+        name="phase1_map",
         interpret=interpret,
     )(
         avail.reshape(1, Mp), p_dyn.reshape(1, Mp), qfree.reshape(1, Mp),

@@ -111,7 +111,8 @@ def test_dynamics_none_bit_exact_with_pr6_snapshot():
         d, h = key.split("/")
         m, aux = engine.simulate(tr, SPEC2, h, observers=("task_log",),
                                  dispatcher=d, dynamics="none")
-        for f in m._fields:
+        # the snapshot's own fields: Metrics has gained fields since
+        for f in [f for f in want if f != "task_log"]:
             got = np.asarray(getattr(m, f), np.float32)
             ref = np.asarray(want[f], np.float32)
             assert got.tobytes() == ref.tobytes(), f"{key}/{f}"

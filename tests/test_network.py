@@ -165,7 +165,8 @@ def test_network_none_bit_exact_with_pr8_snapshot():
         d, h = key.split("/")
         m, aux = engine.simulate(tr, SPEC2, h, observers=("task_log",),
                                  dispatcher=d, network="none")
-        for f in m._fields:
+        # the snapshot's own fields: Metrics has gained fields since
+        for f in [f for f in want if f != "task_log"]:
             got = np.asarray(getattr(m, f), np.float32)
             ref = np.asarray(want[f], np.float32)
             assert got.tobytes() == ref.tobytes(), f"{key}/{f}"

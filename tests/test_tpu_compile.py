@@ -46,9 +46,11 @@ def shape(topo):
                                                     sharding=one_chip)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernel: str):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text  # a Mosaic kernel, not interpreted
+    # under its stable name, which a profile of the chip shows
+    assert f"%{kernel}" in text
     return text
 
 
@@ -72,21 +74,23 @@ def test_map_decide_compiles(shape, heuristic, n, mp):
     kinds = _kinds(heuristic)
     _compile(lambda *a: fused.map_decide_padded(
         *a, n_machines=mp, interpret=False, **kinds),
-        *_map_decide_args(shape, n, mp))
+        *_map_decide_args(shape, n, mp), kernel="map_decide")
 
 
 def test_map_decide_vmapped_compiles(shape):
     """The engine vmaps the simulator, so each call gains a batch axis."""
     body = jax.vmap(lambda *a: fused.map_decide_padded(
         *a, n_machines=4, interpret=False, **_kinds("FELARE")))
-    _compile(body, *_map_decide_args(shape, 2048, 128, batch=(8,)))
+    _compile(body, *_map_decide_args(shape, 2048, 128, batch=(8,)),
+             kernel="map_decide")
 
 
 def test_evict_stats_compiles(shape):
     n, mp = 2048, 128
     _compile(lambda *a: fused.evict_stats_padded(*a, interpret=False),
              shape((mp,), F32), shape((mp,), I32), shape((SP, mp), F32),
-             shape((n,), F32), shape((n,), I32), shape((n,), I32))
+             shape((n,), F32), shape((n,), I32), shape((n,), I32),
+             kernel="evict_stats")
 
 
 def test_balance_scan_compiles(shape):
@@ -94,11 +98,12 @@ def test_balance_scan_compiles(shape):
     _compile(lambda *a: fused.balance_scan_padded(
         *a, n_tasks=2000, interpret=False),
         shape((fp,), I32), shape((n,), I32), shape((n,), I32),
-        shape((n,), I32))
+        shape((n,), I32), kernel="balance_scan")
 
 
 def test_phase1_map_compiles(shape):
     n, mp = 2048, 128
     _compile(lambda *a: phase1_map_padded(*a, interpret=False),
              shape((mp,), F32), shape((mp,), F32), shape((mp,), I32),
-             shape((n, mp), F32), shape((n,), F32), shape((n,), I32))
+             shape((n, mp), F32), shape((n,), F32), shape((n,), I32),
+             kernel="phase1_map")
