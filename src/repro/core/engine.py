@@ -134,6 +134,22 @@ def _init_state(trace: Trace, n_machines: int, queue_size: int,
     )
 
 
+def _type_onehot(task_type: jnp.ndarray, n_types: int) -> jnp.ndarray:
+    """The (N, S) grid ``task_type[k] == s``: fixed for a trace, so the
+    loop builds it once and every N-wide per-type count reads it."""
+    return task_type[:, None] == jnp.arange(n_types, dtype=task_type.dtype)
+
+
+def _count_by_type(mask: jnp.ndarray, type_onehot: jnp.ndarray) -> jnp.ndarray:
+    """Per-type int32 count of an (N,) task mask, as a compare-and-sum.
+
+    ``segment_sum(mask, task_type, S)`` gives the same integers, but
+    under ``vmap`` it lowers to one scatter-add of B x N updates, which
+    the TPU applies one after another on every event; this is a reduce.
+    """
+    return jnp.sum(mask[:, None] & type_onehot, axis=0, dtype=jnp.int32)
+
+
 def _next_event_time(st: SimState, trace: Trace,
                      halted: Optional[jnp.ndarray] = None,
                      wake_ts: Optional[jnp.ndarray] = None) -> jnp.ndarray:
@@ -203,7 +219,7 @@ def _stage_finalize(st: SimState, trace: Trace, sysarr: SystemArrays):
     )
 
 
-def _stage_admit(st: SimState, trace: Trace,
+def _stage_admit(st: SimState, trace: Trace, type_onehot: jnp.ndarray,
                  halted: Optional[jnp.ndarray] = None):
     """Admit newly-arrived tasks to the arriving queue.
 
@@ -217,23 +233,20 @@ def _stage_admit(st: SimState, trace: Trace,
     if halted is not None:
         newly = newly & ~halted
     status = jnp.where(newly, PENDING, st.status)
-    arrived = st.arrived + jax.ops.segment_sum(
-        newly.astype(jnp.int32), trace.task_type, st.arrived.shape[0]
-    )
+    arrived = st.arrived + _count_by_type(newly, type_onehot)
     st = st._replace(status=status, arrived=arrived)
     if halted is None:
         return st
-    return _halt_shutdown(st, trace, halted)
+    return _halt_shutdown(st, trace, type_onehot, halted)
 
 
-def _halt_shutdown(st: SimState, trace: Trace, halted: jnp.ndarray):
+def _halt_shutdown(st: SimState, trace: Trace, type_onehot: jnp.ndarray,
+                   halted: jnp.ndarray):
     """Cancel pending tasks and flush local queues once ``halted``."""
     n, n_types = st.status.shape[0], st.cancelled.shape[0]
     drop = halted & (st.status == PENDING)
     status = jnp.where(drop, CANCELLED, st.status)
-    cancelled = st.cancelled + jax.ops.segment_sum(
-        drop.astype(jnp.int32), trace.task_type, n_types
-    )
+    cancelled = st.cancelled + _count_by_type(drop, type_onehot)
     victim = halted & (st.queue >= 0)
     vidx = jnp.where(victim, st.queue, n)  # OOB sentinel -> dropped
     status = status.at[vidx.reshape(-1)].set(CANCELLED, mode="drop")
@@ -404,8 +417,8 @@ def _stage_faults(st: SimState, trace: Trace, sysarr: SystemArrays,
 
 def _stage_dispatch(st: SimState, trace: Trace, sysarr: SystemArrays,
                     dispatcher, site_of_machine: np.ndarray, n_sites: int,
-                    fairness_factor: float, health: bool = False,
-                    net=None):
+                    fairness_factor: float, type_onehot: jnp.ndarray,
+                    health: bool = False, net=None):
     """Assign newly-admitted tasks to federation sites (dispatch-once).
 
     A task is dispatched at the first event where it is PENDING and still
@@ -482,15 +495,14 @@ def _stage_dispatch(st: SimState, trace: Trace, sysarr: SystemArrays,
     stale = ((st.status == PENDING) & (ready > st.now)
              & (st.now >= trace.deadline))
     status = jnp.where(stale, CANCELLED, st.status)
-    cancelled = st.cancelled + jax.ops.segment_sum(
-        stale.astype(jnp.int32), trace.task_type, st.cancelled.shape[0]
-    )
+    cancelled = st.cancelled + _count_by_type(stale, type_onehot)
     return st._replace(ready=ready, e_dyn=st.e_dyn + pay.sum(),
                        e_xfer=e_xfer, status=status, cancelled=cancelled)
 
 
 def _stage_map(st: SimState, trace: Trace, sysarr: SystemArrays,
-               select_fn: Callable, fairness_factor: float, n_types: int,
+               select_fn: Callable, fairness_factor: float,
+               type_onehot: jnp.ndarray,
                site_members: Optional[np.ndarray] = None,
                site_of_machine: Optional[np.ndarray] = None,
                health: bool = False, backup_k: int = 0):
@@ -518,7 +530,7 @@ def _stage_map(st: SimState, trace: Trace, sysarr: SystemArrays,
     """
     action = _map_action(st, trace, sysarr, select_fn, fairness_factor,
                          site_members, site_of_machine, health)
-    st2 = _apply_action(st, trace, action, n_types)
+    st2 = _apply_action(st, trace, action, type_onehot)
     if backup_k > 0:
         st2 = _nominate_backups(st2, trace, sysarr, action, backup_k)
     return st2
@@ -691,9 +703,11 @@ def _map_action(st: SimState, trace: Trace, sysarr: SystemArrays,
     return MapAction(assign, drop, queue_drop)
 
 
-def _apply_action(st: SimState, trace: Trace, action, n_types: int):
+def _apply_action(st: SimState, trace: Trace, action,
+                  type_onehot: jnp.ndarray):
     """Apply a MapAction: queue evictions, proactive drops, assignments."""
     M, Q = st.queue.shape
+    n_types = st.cancelled.shape[0]
     # --- queue evictions (FELARE victims) -> CANCELLED ----------------------
     victim = action.queue_drop & (st.queue >= 0)
     vidx = jnp.where(victim, st.queue, st.status.shape[0])
@@ -712,9 +726,7 @@ def _apply_action(st: SimState, trace: Trace, action, n_types: int):
     # --- proactive drops from the arriving queue ----------------------------
     drop = action.drop & (status == PENDING)
     status = jnp.where(drop, CANCELLED, status)
-    cancelled = cancelled + jax.ops.segment_sum(
-        drop.astype(jnp.int32), trace.task_type, n_types
-    )
+    cancelled = cancelled + _count_by_type(drop, type_onehot)
 
     # --- assignments: append to queue tails ---------------------------------
     assign = action.assign  # (M,)
@@ -928,6 +940,7 @@ def make_simulator(select_fn: Callable, sysarr: SystemArrays, *,
                    if health else None)
         wake_ts = (jnp.asarray(wake, jnp.float32) * horizon
                    if wake else None)
+        type_onehot = _type_onehot(trace.task_type, S)
 
         def cond(est: EngineState):
             st, aux = est
@@ -952,7 +965,7 @@ def make_simulator(select_fn: Callable, sysarr: SystemArrays, *,
                 st = _stage_finalize(st, trace, sysarr)
             aux = notify("finalize", aux, st)
             with jax.named_scope("engine.admit"):
-                st = _stage_admit(st, trace, halted)
+                st = _stage_admit(st, trace, type_onehot, halted)
             aux = notify("admit", aux, st)
             if health:
                 with jax.named_scope("engine.faults"):
@@ -961,11 +974,13 @@ def make_simulator(select_fn: Callable, sysarr: SystemArrays, *,
                 aux = notify("faults", aux, st)
             with jax.named_scope("engine.dispatch"):
                 st = _stage_dispatch(st, trace, sysarr, dispatcher, sites_np,
-                                     n_sites, fairness_factor, health, net)
+                                     n_sites, fairness_factor, type_onehot,
+                                     health, net)
             aux = notify("dispatch", aux, st)
             with jax.named_scope("engine.map"):
                 st = _stage_map(st, trace, sysarr, select_fn, fairness_factor,
-                                S, site_members, sites_np, health, backup_k)
+                                type_onehot, site_members, sites_np, health,
+                                backup_k)
             aux = notify("map", aux, st)
             with jax.named_scope("engine.start"):
                 st = _stage_start(st, trace, sysarr, health)

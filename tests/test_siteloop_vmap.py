@@ -90,6 +90,7 @@ def _comparator(n_sites: int, heuristic: str, k: int = 10):
 
     def compare(trace, assigned):
         stt = engine._init_state(trace, M, Q, S)
+        onehot = engine._type_onehot(trace.task_type, S)
         oks = []
         for _ in range(k):
             t = engine._next_event_time(stt, trace)
@@ -99,15 +100,15 @@ def _comparator(n_sites: int, heuristic: str, k: int = 10):
             t = jnp.where(jnp.isfinite(t), t, stt.now)
             stt = stt._replace(now=jnp.maximum(t, stt.now))
             stt = engine._stage_finalize(stt, trace, sysarr)
-            stt = engine._stage_admit(stt, trace)
+            stt = engine._stage_admit(stt, trace, onehot)
             new = (stt.status == engine.PENDING) & (stt.site < 0)
             stt = stt._replace(site=jnp.where(new, assigned, stt.site))
             a_new = engine._map_action(stt, trace, sysarr, pol, ff,
                                        members, sites_np)
             a_old = legacy.map_action_unrolled(stt, trace, sysarr, pol, ff,
                                                members)
-            st_new = engine._apply_action(stt, trace, a_new, S)
-            st_old = engine._apply_action(stt, trace, a_old, S)
+            st_new = engine._apply_action(stt, trace, a_new, onehot)
+            st_old = engine._apply_action(stt, trace, a_old, onehot)
             oks.append(jnp.stack(
                 [jnp.array_equal(x, y) for x, y in
                  zip(jax.tree.leaves(a_new), jax.tree.leaves(a_old))]
@@ -173,7 +174,7 @@ def test_event_level_map_parity_property(combo, seed, rate):
 
 # ------------------------------------------------------------ trace level
 def _legacy_stage_map(st_, trace, sysarr, select_fn, fairness_factor,
-                      n_types, site_members=None, site_of_machine=None,
+                      type_onehot, site_members=None, site_of_machine=None,
                       health=False, backup_k=0):
     """Signature shim: the live engine body -> the frozen PR 5 unroll.
 
@@ -183,7 +184,8 @@ def _legacy_stage_map(st_, trace, sysarr, select_fn, fairness_factor,
     """
     assert not health and backup_k == 0
     return legacy.stage_map_unrolled(st_, trace, sysarr, select_fn,
-                                     fairness_factor, n_types, site_members)
+                                     fairness_factor, type_onehot,
+                                     site_members)
 
 
 @functools.lru_cache(maxsize=None)
