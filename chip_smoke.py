@@ -16,12 +16,25 @@ comes out by the repo's own means:
   federation ``paper_x32`` (128 machines, 32 sites), bursty traffic,
              ``fair_spill`` dispatch, ELARE/FELARE: conservation, the
              dispatcher's compiled balance scan, lax == fused byte for byte;
+  eq3        the fairness monitor against IEEE float32 (``--eq3``, below);
   oracle     dyadic-rounded traces generated on the chip (``paper``: 2 x
              2000 tasks, MM/ELARE/FELARE; ``paper_x32``: 1 x 500 tasks,
              FELARE/``fair_spill``, cut to keep the run short), simulated
              there and by the plain-Python oracle ``repro.core.pyengine``
              in a host thread while the sweeps above run: per-type
              counters equal, energies and makespan within 1e-3.
+
+``--eq3`` runs only the Eq. 3 check: the program's fairness monitor on
+the device against numpy's IEEE float32, the rule of ``bench/reference.py``
+(the completion rate of every count pair 0 <= c <= a <= 2048, the root on
+10**6 seeded values and every variance below, f32 add and multiply on 10**6
+seeded pairs, ``equations.fairness_limit`` at f = 1.5 on the seeded
+tuples' rates, and ``fairness.suffered_types`` on every equal-pairs tuple
+with arrivals <= 64 and on 10**5 seeded tuples at f = 0.5, 1, 2). It
+prints each disagreement count and fails unless all are 0. It reads only
+``fairness.completion_rates`` and ``suffered_types``,
+``equations.fairness_limit`` and ``equations.sqrt_f32`` where the program
+has it (else ``jnp.sqrt``), so it runs against an older program too.
 
 ``--four-chips`` runs only the sharded sweep: the federation spec (lax,
 with the ``task_log`` observer) through ``run_sweep(shard=True)`` over a
@@ -34,6 +47,7 @@ seconds are information, not benchmark metrics.
 
     python chip_smoke.py                 # one chip
     python chip_smoke.py --four-chips    # four chips
+    python chip_smoke.py --eq3           # the Eq. 3 check alone
     JAX_PLATFORMS=cpu python chip_smoke.py --cpu-rehearsal
         # tiny sizes on the CPU, kernels interpreted; never reports ok
 """
@@ -332,6 +346,124 @@ def phase_oracle(cases) -> None:
                       f"(oracle {host_s:.1f} s on the host)")
 
 
+EQ3_FACTORS = (0.5, 1.0, 2.0)
+EQ3_TYPES = 4
+
+
+def eq3_equal_pairs(max_arrivals: int = 64):
+    """Every count tuple whose rates are p, q, p, q in some arrangement,
+    p < q, arrivals <= ``max_arrivals``: mean - std equals p exactly, so
+    the mask rests on the last bit. The float32 quotient depends on c / a
+    alone, so each rate comes once in lowest terms and once as its largest
+    multiple (1/3 as 1 of 3 and 21 of 63). Returns (T, 4) int32 (c, a)."""
+    import math
+
+    import numpy as np
+
+    fr = sorted(((c, a) for a in range(1, max_arrivals + 1)
+                 for c in range(a + 1) if math.gcd(c, a) == 1),
+                key=lambda x: x[0] / x[1])
+    low = np.asarray(fr, np.int32)
+    high = low * (max_arrivals // low[:, 1:2])
+    i, j = np.triu_indices(len(fr), k=1)
+    cs, as_ = [], []
+    for slots in ((0, 1), (0, 2), (0, 3), (1, 2), (1, 3), (2, 3)):
+        other = [t for t in range(EQ3_TYPES) if t not in slots]
+        c = np.empty((len(i), EQ3_TYPES), np.int32)
+        a = np.empty_like(c)
+        for t, rep, idx in ((slots[0], low, i), (slots[1], high, i),
+                            (other[0], low, j), (other[1], high, j)):
+            c[:, t], a[:, t] = rep[idx, 0], rep[idx, 1]
+        cs.append(c)
+        as_.append(a)
+    return np.concatenate(cs), np.concatenate(as_)
+
+
+def eq3_seeded(n: int = 10**5, seed: int = 15, max_arrivals: int = 2000):
+    """n seeded tuples: arrivals uniform in [0, max_arrivals], completions
+    uniform in [0, arrivals]."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    a = rng.integers(0, max_arrivals + 1, (n, EQ3_TYPES)).astype(np.int32)
+    c = np.floor(rng.random((n, EQ3_TYPES)) * (a + 1)).astype(np.int32)
+    return c, a
+
+
+def eq3_numpy(c, a, f):
+    """IEEE float32 Eq. 3 over rows of counts, as ``bench/reference.py``
+    rounds it: ``R(R(c) / R(a))`` (1.0 without arrivals), numpy's float32
+    ``mean`` and ``std``, ``max(R(mu - R(f * sigma)), 0)``, suffered where
+    ``cr <= eps`` and arrivals >= 1. Returns (rates, variances, mask)."""
+    import numpy as np
+
+    f32 = np.float32
+    cr = np.where(a > 0, c.astype(f32) / np.maximum(a, 1).astype(f32),
+                  f32(1.0))
+    mu = np.mean(cr, axis=1, dtype=f32)
+    var = np.var(cr, axis=1, dtype=f32)
+    eps = np.maximum(mu - f32(f) * np.sqrt(var), f32(0.0))
+    return cr, var, (cr <= eps[:, None]) & (a >= 1)
+
+
+def phase_eq3(rehearsal: bool) -> dict:
+    """Disagreements of the device's Eq. 3 with numpy's IEEE float32."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import equations, fairness
+
+    f32 = np.float32
+    top, n_rand = (64, 10**4) if rehearsal else (2048, 10**6)
+    rng = np.random.default_rng(2206)
+    counts = {}
+
+    def bits(x):
+        return np.asarray(x, f32).view(np.int32)
+
+    g = np.arange(top + 1)
+    c, a = np.meshgrid(g, g, indexing="ij")
+    keep = (c <= a) & (a > 0)
+    c, a = c[keep].astype(np.int32), a[keep].astype(np.int32)
+    got = jax.jit(fairness.completion_rates)(c, a)
+    counts["ratio"] = int((bits(got) != bits(c.astype(f32)
+                                                / a.astype(f32))).sum())
+
+    x = rng.random(n_rand).astype(f32)
+    y = (rng.random(n_rand) * 4).astype(f32)
+    counts["add"] = int((bits(jax.jit(jnp.add)(x, y)) != bits(x + y)).sum())
+    counts["multiply"] = int((bits(jax.jit(jnp.multiply)(x, y))
+                              != bits(x * y)).sum())
+
+    tuples = {"equal_pairs": eq3_equal_pairs(),
+              "seeded": eq3_seeded(10**4 if rehearsal else 10**5)}
+    root = getattr(equations, "sqrt_f32", jnp.sqrt)
+    v = [np.exp2(rng.uniform(-40.0, 0.0, n_rand)).astype(f32)]
+    v += [eq3_numpy(c, a, 1.0)[1] for c, a in tuples.values()]
+    v = np.concatenate(v)
+    counts["root"] = int((bits(jax.jit(root)(v)) != bits(np.sqrt(v))).sum())
+
+    cr = eq3_numpy(*tuples["seeded"], 1.0)[0]
+    limit = jax.jit(jax.vmap(equations.fairness_limit, (0, None)),
+                    static_argnums=1)(cr, 1.5)
+    mu = np.mean(cr, axis=1, dtype=f32)
+    eps = np.maximum(mu - f32(1.5) * np.std(cr, axis=1, dtype=f32), f32(0))
+    counts["limit.seeded.f1.5"] = int((bits(limit) != bits(eps)).sum())
+
+    mask = jax.jit(jax.vmap(fairness.suffered_types, (0, 0, None)),
+                   static_argnums=2)
+    for name, (c, a) in tuples.items():
+        for f in EQ3_FACTORS:
+            got = np.asarray(mask(c, a, f))
+            want = eq3_numpy(c, a, f)[2]
+            counts[f"suffered.{name}.f{f}"] = int((got != want).any(1).sum())
+    say("eq3", json.dumps(counts))
+    bad = {k: v for k, v in counts.items() if v}
+    check(not bad, f"Eq. 3 disagrees with IEEE float32: {bad}")
+    return counts
+
+
 def phase_four_chips(sz) -> None:
     import jax
 
@@ -354,6 +486,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the sharded sweep on a 4-device mesh")
+    ap.add_argument("--eq3", action="store_true",
+                    help="run only the Eq. 3 check against IEEE float32")
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="tiny sizes on the CPU with interpreted kernels; "
                          "never reports ok")
@@ -373,9 +507,12 @@ def main(argv=None) -> int:
     oracle = []
     if args.four_chips:
         phases = [("four-chips", lambda: phase_four_chips(sz))]
+    elif args.eq3:
+        phases = [("eq3", lambda: phase_eq3(args.cpu_rehearsal))]
     else:
         phases = [("oracle traces",
                    lambda: oracle.extend(start_oracle(sz, pool))),
+                  ("eq3", lambda: phase_eq3(args.cpu_rehearsal)),
                   ("paper", lambda: phase_paper(sz, args.cpu_rehearsal)),
                   ("federation",
                    lambda: phase_federation(sz, args.cpu_rehearsal)),

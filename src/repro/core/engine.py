@@ -99,7 +99,8 @@ STAGES = ("finalize", "admit", "faults", "dispatch", "map", "start")
 
 def _init_state(trace: Trace, n_machines: int, queue_size: int,
                 n_types: int, *, backup_k: int = 0,
-                network: bool = False, n_tiers: int = 1) -> SimState:
+                network: bool = False, n_tiers: int = 1,
+                fair: bool = False) -> SimState:
     n = trace.arrival.shape[0]
     M, Q, S = n_machines, queue_size, n_types
     f = jnp.float32
@@ -131,7 +132,17 @@ def _init_state(trace: Trace, n_machines: int, queue_size: int,
         # the pre-network one.
         ready=(trace.arrival.astype(f) if network else None),
         e_xfer=(jnp.zeros((n_tiers,), f) if network else None),
+        # fairness counters only where the policy reads the mask
+        fair_evicted=(jnp.int32(0) if fair else None),
+        suffered_maps=(jnp.int32(0) if fair else None),
     )
+
+
+def _uses_fairness(select_fn) -> bool:
+    """Whether the policy reads the suffered-type mask: a composed policy
+    says so in its description; an opaque callable may, so it gets one."""
+    describe = getattr(select_fn, "describe", None)
+    return describe is None or bool(describe().fairness)
 
 
 def _type_onehot(task_type: jnp.ndarray, n_types: int) -> jnp.ndarray:
@@ -505,7 +516,8 @@ def _stage_map(st: SimState, trace: Trace, sysarr: SystemArrays,
                type_onehot: jnp.ndarray,
                site_members: Optional[np.ndarray] = None,
                site_of_machine: Optional[np.ndarray] = None,
-               health: bool = False, backup_k: int = 0):
+               health: bool = False, backup_k: int = 0,
+               suffered: Optional[jnp.ndarray] = None):
     """Run the per-site mapping policy and apply the combined MapAction.
 
     ``site_members`` is the (F, M) partition grid — a host constant whose
@@ -529,7 +541,7 @@ def _stage_map(st: SimState, trace: Trace, sysarr: SystemArrays,
     ops), keeping flat runs bit-exact.
     """
     action = _map_action(st, trace, sysarr, select_fn, fairness_factor,
-                         site_members, site_of_machine, health)
+                         site_members, site_of_machine, health, suffered)
     st2 = _apply_action(st, trace, action, type_onehot)
     if backup_k > 0:
         st2 = _nominate_backups(st2, trace, sysarr, action, backup_k)
@@ -578,8 +590,12 @@ def _map_action(st: SimState, trace: Trace, sysarr: SystemArrays,
                 select_fn: Callable, fairness_factor: float,
                 site_members: Optional[np.ndarray] = None,
                 site_of_machine: Optional[np.ndarray] = None,
-                health: bool = False) -> MapAction:
+                health: bool = False,
+                suffered: Optional[jnp.ndarray] = None) -> MapAction:
     """The combined :class:`MapAction` of one mapping event (pre-apply).
+
+    ``suffered`` is this event's Eq. 3 mask where the caller has it
+    already (:func:`_suffered` otherwise).
 
     With ``health`` the machine view is masked *before* the single-site /
     block-diagonal / masked-vmap split: dead machines read avail=BIG,
@@ -589,9 +605,8 @@ def _map_action(st: SimState, trace: Trace, sysarr: SystemArrays,
     policy-side code (in particular ``stale_hopeless`` cancels a dead
     site's pending tasks: its fastest machine reads BIG).
     """
-    suffered = fairness.suffered_types(
-        st.completed, st.arrived, fairness_factor
-    )
+    if suffered is None:
+        suffered = _suffered(st, select_fn, fairness_factor)
     pending = st.status == PENDING
     if st.ready is not None:
         # network subsystem: in-transit tasks (dispatched, not yet landed
@@ -703,6 +718,16 @@ def _map_action(st: SimState, trace: Trace, sysarr: SystemArrays,
     return MapAction(assign, drop, queue_drop)
 
 
+def _suffered(st: SimState, select_fn: Callable, fairness_factor: float):
+    """The Eq. 3 monitor (Alg. 4) at this event; all False, and no ops,
+    for a policy that never reads it."""
+    if not _uses_fairness(select_fn):
+        return jnp.zeros(st.completed.shape, bool)
+    with jax.named_scope("engine.fairness"):
+        return fairness.suffered_types(st.completed, st.arrived,
+                                       fairness_factor)
+
+
 def _apply_action(st: SimState, trace: Trace, action,
                   type_onehot: jnp.ndarray):
     """Apply a MapAction: queue evictions, proactive drops, assignments."""
@@ -717,6 +742,9 @@ def _apply_action(st: SimState, trace: Trace, action,
         trace.task_type[jnp.clip(vidx, 0, st.status.shape[0] - 1)].reshape(-1),
         n_types,
     )
+    if st.fair_evicted is not None:
+        st = st._replace(fair_evicted=st.fair_evicted
+                         + victim.sum(dtype=jnp.int32))
     # compact queues (stable: keep FCFS order of survivors)
     keep = ~victim & (st.queue >= 0)
     order = jnp.argsort(~keep, axis=1, stable=True)  # survivors first
@@ -864,6 +892,7 @@ def make_simulator(select_fn: Callable, sysarr: SystemArrays, *,
     from repro.core import network as network_mod
 
     S, M = sysarr.eet.shape
+    fair = _uses_fairness(select_fn)
     dynamics = faults_mod.resolve(dynamics)
     if getattr(dynamics, "kind", None) == "none":
         dynamics = None
@@ -923,7 +952,7 @@ def make_simulator(select_fn: Callable, sysarr: SystemArrays, *,
         netted = network is not None
         st = _init_state(trace, M, queue_size, S, backup_k=backup_k,
                          network=netted,
-                         n_tiers=n_tiers if netted else 1)
+                         n_tiers=n_tiers if netted else 1, fair=fair)
         if netted:
             # Per-task (N, F) link costs, gathered once outside the loop:
             # row k prices task k's origin (a salted counter hash over the
@@ -978,9 +1007,14 @@ def make_simulator(select_fn: Callable, sysarr: SystemArrays, *,
                                      health, net)
             aux = notify("dispatch", aux, st)
             with jax.named_scope("engine.map"):
+                suffered = (_suffered(st, select_fn, fairness_factor)
+                            if fair else None)
                 st = _stage_map(st, trace, sysarr, select_fn, fairness_factor,
                                 type_onehot, site_members, sites_np, health,
-                                backup_k)
+                                backup_k, suffered=suffered)
+                if fair:
+                    st = st._replace(suffered_maps=st.suffered_maps
+                                     + jnp.any(suffered).astype(jnp.int32))
             aux = notify("map", aux, st)
             with jax.named_scope("engine.start"):
                 st = _stage_start(st, trace, sysarr, health)
@@ -1000,6 +1034,8 @@ def make_simulator(select_fn: Callable, sysarr: SystemArrays, *,
             energy_idle=e_idle,
             makespan=makespan,
             steps=st.steps,
+            fair_evicted=st.fair_evicted if fair else jnp.int32(0),
+            suffered_maps=st.suffered_maps if fair else jnp.int32(0),
         )
         if not observers:
             return metrics

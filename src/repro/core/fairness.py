@@ -1,21 +1,34 @@
-"""Fairness measure over task types (Sec. V, Algorithm 4)."""
+"""Fairness measure over task types (Sec. V, Algorithm 4).
+
+The rates and the mask are jitted: Eq. 3's exact float32 arithmetic is a
+few hundred integer ops, and a jitted function is traced once per input
+shape and reused by every sweep that traces it again.
+"""
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 
 from repro.core import equations
 
 
+@jax.jit
 def completion_rates(completed_by_type, arrived_by_type):
     """cr_i = on-time completions of type i / arrivals of type i (so far).
 
     Types with no arrivals yet report rate 1.0 (they cannot have suffered).
+    The quotient is IEEE float32 on every backend
+    (:func:`~repro.core.equations.ratio_f32`), for counts below 2**30.
     """
-    arrived = jnp.asarray(arrived_by_type)
-    completed = jnp.asarray(completed_by_type)
-    return jnp.where(arrived > 0, completed / jnp.maximum(arrived, 1), 1.0)
+    arrived = jnp.asarray(arrived_by_type).astype(jnp.int32)
+    completed = jnp.asarray(completed_by_type).astype(jnp.int32)
+    rate = equations.ratio_f32(completed, jnp.maximum(arrived, 1))
+    return jnp.where(arrived > 0, rate, jnp.float32(1.0))
 
 
+@functools.partial(jax.jit, static_argnames=("min_arrivals",))
 def suffered_types(completed_by_type, arrived_by_type, fairness_factor,
                    min_arrivals: int = 1):
     """Algorithm 4 — the suffered-task-type mask.

@@ -61,7 +61,8 @@ class SchedContext:
     deadline: jnp.ndarray    # (N,) f32
     view: MachineView
     sysarr: SystemArrays
-    suffered: jnp.ndarray    # (S,) bool — fairness monitor (Alg. 4)
+    suffered: jnp.ndarray    # (S,) bool — fairness monitor (Alg. 4); all
+    #   False for a policy whose description says no fairness
 
     # -- static shapes ------------------------------------------------------
     @property

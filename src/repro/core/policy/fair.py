@@ -13,12 +13,15 @@ variant; FELARE is exactly ``with_fairness(ELARE)``. The wrapper adds:
      degradation").
 
 Phase-I and the drop rule are the base policy's own, re-run against the
-post-eviction machine state via ``SchedContext.with_view``.
+post-eviction machine state via ``SchedContext.with_view``. The eviction
+plan and the priority Phase-II run under ``jax.named_scope(
+"engine.fairness")``, like the engine's Eq. 3 monitor.
 """
 from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import equations
@@ -111,10 +114,11 @@ class FairnessPolicy:
     base: TwoPhasePolicy
 
     def select(self, ctx: SchedContext) -> MapAction:
-        qdrop = _plan_eviction(ctx)
+        with jax.named_scope("engine.fairness"):
+            qdrop = _plan_eviction(ctx)
+            ctx2 = ctx.with_view(_evicted_view(ctx, qdrop))
 
         # Re-run the base policy's Phase-I against post-eviction state.
-        ctx2 = ctx.with_view(_evicted_view(ctx, qdrop))
         nom = self.base.nominator.nominate(ctx2)
         nominee = nom.grid(ctx2)
         key = jnp.broadcast_to(
@@ -122,12 +126,13 @@ class FairnessPolicy:
         )
 
         # Priority Phase-II: suffered-type nominees claim machines first.
-        hi = nominee & ctx.suffered_tasks[:, None]
-        assign_hi = phase2(hi, key, ctx2.qfree)
-        taken = assign_hi >= 0
-        lo = nominee & ~ctx.suffered_tasks[:, None]
-        assign_lo = phase2(lo, key, ctx2.qfree & ~taken)
-        assign = jnp.where(taken, assign_hi, assign_lo)
+        with jax.named_scope("engine.fairness"):
+            hi = nominee & ctx.suffered_tasks[:, None]
+            assign_hi = phase2(hi, key, ctx2.qfree)
+            taken = assign_hi >= 0
+            lo = nominee & ~ctx.suffered_tasks[:, None]
+            assign_lo = phase2(lo, key, ctx2.qfree & ~taken)
+            assign = jnp.where(taken, assign_hi, assign_lo)
 
         return finalize(ctx, assign, self.base.drop_rule.drop(ctx), qdrop)
 

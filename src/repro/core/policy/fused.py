@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.policy import fair as fair_mod
@@ -76,12 +77,13 @@ class FusedMapPolicy:
 
         desc = self.base.describe()
         if desc.fairness:
-            task_feas_now, min_exec = map_fused.evict_stats(
-                ctx.start, ctx.qfree, ctx.sysarr.eet, ctx.deadline,
-                ctx.pending, ctx.task_type, interpret=self.interpret)
-            qdrop = fair_mod._plan_eviction_from_stats(
-                ctx, task_feas_now, min_exec)
-            ctx2 = ctx.with_view(fair_mod._evicted_view(ctx, qdrop))
+            with jax.named_scope("engine.fairness"):
+                task_feas_now, min_exec = map_fused.evict_stats(
+                    ctx.start, ctx.qfree, ctx.sysarr.eet, ctx.deadline,
+                    ctx.pending, ctx.task_type, interpret=self.interpret)
+                qdrop = fair_mod._plan_eviction_from_stats(
+                    ctx, task_feas_now, min_exec)
+                ctx2 = ctx.with_view(fair_mod._evicted_view(ctx, qdrop))
             suffered_task = ctx.suffered_tasks
         else:
             qdrop = None

@@ -273,6 +273,7 @@ def simulate(trace, spec, heuristic: str, dispatcher=None, dynamics=None,
     missed = np.zeros(S, int)
     cancelled = np.zeros(S, int)
     arrived = np.zeros(S, int)
+    fair_evicted = suffered_maps = 0  # the fairness step's counters
     e_dyn = 0.0
     e_wasted = 0.0
     now = 0.0
@@ -454,12 +455,15 @@ def simulate(trace, spec, heuristic: str, dispatcher=None, dynamics=None,
                 _end(k)
 
     def mapping_event():
+        nonlocal suffered_maps
         suffered = suffered_mask()
+        if desc.fairness:
+            suffered_maps += bool(suffered.any())
         for s in range(F_sites):
             _map_site(s, suffered)
 
     def _map_site(s, suffered):
-        nonlocal status
+        nonlocal status, fair_evicted
         msite = site_machines[s]
         pend = [k for k in range(n)
                 if status[k] == PENDING and task_site[k] == s
@@ -506,6 +510,7 @@ def simulate(trace, spec, heuristic: str, dispatcher=None, dynamics=None,
                         evict.append(qi)
                         rem = F(rem - eet_c[ttype[t], mstar])
                 if F(F(base + rem) + e_tgt) <= dl[k]:
+                    fair_evicted += len(evict)
                     for qi in evict:
                         t = m.queue.pop(qi)
                         status[t] = CANCELLED
@@ -729,6 +734,8 @@ def simulate(trace, spec, heuristic: str, dispatcher=None, dynamics=None,
         energy_wasted=e_wasted,
         energy_idle=e_idle,
         makespan=makespan,
+        fair_evicted=fair_evicted,
+        suffered_maps=suffered_maps,
         backup=backup.copy(),
         task_log=dict(
             map_time=log_map,

@@ -212,6 +212,10 @@ class SimState(NamedTuple):
     With ``network="none"`` both stay ``None`` — absent pytree leaves,
     so the traced program is structurally identical to the pre-network
     engine.
+
+    ``fair_evicted`` and ``suffered_maps`` accumulate the matching
+    :class:`Metrics` counters; they stay ``None`` for a policy without
+    fairness, so its loop carries and computes nothing for them.
     """
 
     now: jnp.ndarray            # ()
@@ -238,6 +242,8 @@ class SimState(NamedTuple):
     backup: Optional[jnp.ndarray] = None    # (N, k) int32 backup machines
     ready: Optional[jnp.ndarray] = None     # (N,) f32 ready time at site
     e_xfer: Optional[jnp.ndarray] = None    # (T,) f32 transfer energy by tier
+    fair_evicted: Optional[jnp.ndarray] = None   # () int32, fairness only
+    suffered_maps: Optional[jnp.ndarray] = None  # () int32, fairness only
 
 
 class EngineState(NamedTuple):
@@ -260,6 +266,11 @@ class Metrics(NamedTuple):
     event loop's iterations for this trace (int32). Under ``vmap`` the
     loop runs until its slowest trace ends, so a batch's iterations are
     the max over its lanes, and the rest is lanes left idle.
+
+    ``fair_evicted`` and ``suffered_maps`` count the fairness step (Sec.
+    V): queued tasks evicted for a suffered task (``cancelled_by_type``
+    counts them too), and events whose map stage found at least one type
+    suffered (Eq. 3). Both are constant 0 for a policy without fairness.
     """
 
     completed_by_type: jnp.ndarray  # (S,)
@@ -271,6 +282,8 @@ class Metrics(NamedTuple):
     energy_idle: jnp.ndarray        # () idle energy over the makespan
     makespan: jnp.ndarray           # () time of last event
     steps: jnp.ndarray              # () int32 event-loop iterations
+    fair_evicted: jnp.ndarray       # () int32 fairness evictions
+    suffered_maps: jnp.ndarray      # () int32 events with a suffered type
 
     @property
     def completion_rate_by_type(self):

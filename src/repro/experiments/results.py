@@ -65,9 +65,10 @@ class SweepResult:
       rates: R arrival rates (axis 1).
       metrics: raw Metrics pytree; count leaves are (H, R, K, S) int arrays,
         energy/makespan leaves are (H, R, K) floats, and ``steps`` (the
-        event loop's iterations per trace) is (H, R, K) int32. The CSV and
-        JSON summaries leave ``steps`` out: it is what a trace cost, not
-        what it simulated.
+        event loop's iterations per trace) is (H, R, K) int32, as are the
+        fairness step's ``fair_evicted`` and ``suffered_maps``. The CSV
+        and JSON summaries leave ``steps`` out: it is what a trace cost,
+        not what it simulated.
       aux: observer outputs keyed by observer name (empty dict when the
         spec attached none); every leaf leads with the same (H, R, K)
         batch dims — e.g. the ``timeline`` observer's ``e_dyn`` series is

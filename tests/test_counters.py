@@ -10,7 +10,11 @@ the engine counts with a compare-and-sum over a loop-invariant one-hot
   * no scatter-add of N or more updates is left in the traced loop;
   * every ``Metrics`` field of a vmapped batch equals the values frozen
     from the scatter-based engine
-    (``tests/data/counter_scatter_metrics.json``).
+    (``tests/data/counter_scatter_metrics.json``; the fairness counters
+    ``fair_evicted`` and ``suffered_maps``, added later, were checked
+    against the oracle before they were frozen).
+
+The fairness counters are also compared with the oracle directly.
 """
 import json
 import os
@@ -21,7 +25,7 @@ import numpy as np
 import pytest
 
 from repro import scenarios
-from repro.core import engine, observe, policy
+from repro.core import engine, observe, policy, pyengine
 from repro.core.types import Trace
 
 SNAPSHOT = os.path.join(os.path.dirname(__file__), "data",
@@ -149,3 +153,24 @@ def test_metrics_match_the_scatter_engine(fleet):
             assert got[f].tobytes() == ref.tobytes(), f"{fleet}/{h}/{f}"
         if h in ("ELARE", "FELARE"):  # the proactive drops were counted
             assert int(got["cancelled_by_type"].sum()) > 0, h
+
+
+@pytest.mark.parametrize("fleet", ["paper", "paper_x8"])
+def test_fairness_counters_match_the_oracle(fleet):
+    """FELARE's evictions and suffered mapping events equal the oracle's,
+    trace for trace, at rates 2-8 tasks/s; every other heuristic reads 0."""
+    system = scenarios.get_fleet(fleet).build()
+    tr = _batch(system.eet, 120, seed=11)
+    for h in HEURISTICS:
+        m = engine.simulate_batch(tr, system, h)
+        for f in ("fair_evicted", "suffered_maps"):
+            got = np.asarray(getattr(m, f))
+            assert got.dtype == np.int32 and got.shape == (len(RATES),), f
+            if h != "FELARE":
+                assert not got.any(), (h, f)
+                continue
+            want = [pyengine.simulate(jax.tree.map(lambda x: x[b], tr),
+                                      system, h)[f]
+                    for b in range(len(RATES))]
+            np.testing.assert_array_equal(got, want, f"{fleet}/{h}/{f}")
+            assert got.sum() > 0, f

@@ -175,12 +175,13 @@ def test_event_level_map_parity_property(combo, seed, rate):
 # ------------------------------------------------------------ trace level
 def _legacy_stage_map(st_, trace, sysarr, select_fn, fairness_factor,
                       type_onehot, site_members=None, site_of_machine=None,
-                      health=False, backup_k=0):
+                      health=False, backup_k=0, suffered=None):
     """Signature shim: the live engine body -> the frozen PR 5 unroll.
 
     ``health``/``backup_k`` are the PR 7 faults-subsystem knobs; this
     battery runs without a dynamics attached, where both are inert
-    (False/0), so the frozen unroll simply ignores them.
+    (False/0), so the frozen unroll simply ignores them. ``suffered`` is
+    the event's Eq. 3 mask, which the unroll computes again itself.
     """
     assert not health and backup_k == 0
     return legacy.stage_map_unrolled(st_, trace, sysarr, select_fn,

@@ -56,7 +56,8 @@ def test_sweep_ops_carry_stage_and_heuristic_scopes():
                     dynamics="site_outage").as_text(debug_info=True)
     scopes = {part for op in re.findall(r'loc\("([^"]*)"', text)
               for part in op.split("/")}
-    for scope in STAGE_SCOPES + tuple(f"sweep.{h}" for h in HEURISTICS):
+    for scope in STAGE_SCOPES + ("engine.fairness",) + tuple(
+            f"sweep.{h}" for h in HEURISTICS):
         assert scope in scopes, scope
 
 
@@ -67,8 +68,9 @@ def test_scopes_leave_the_program_unchanged():
     with_scopes = _lowered(SPEC, HEURISTICS)
     with _no_scopes():
         without = _lowered(SPEC, HEURISTICS)
-    assert "engine.map" in with_scopes.as_text(debug_info=True)
-    assert "engine.map" not in without.as_text(debug_info=True)
+    for scope in ("engine.map", "engine.fairness"):
+        assert scope in with_scopes.as_text(debug_info=True), scope
+        assert scope not in without.as_text(debug_info=True), scope
     assert with_scopes.as_text() == without.as_text()
 
 
