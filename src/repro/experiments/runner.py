@@ -12,11 +12,19 @@ program and a single dispatch:
     R rates, K replicates — and so does every leaf of the observer aux
     when the spec attaches engine observers (:mod:`repro.core.observe`):
     telemetry rides inside the same jitted program, never a second pass.
+
+The jitted program is cached on what it closes over statically (see
+:func:`simulate_sweep`), so a repeated sweep with equal static inputs
+re-runs the compiled program without tracing or lowering it again;
+:func:`sweep_cache_info` counts the hits.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import engine, policy
 from repro.core.types import SystemSpec, Trace
@@ -28,17 +36,23 @@ from repro.experiments.spec import SweepSpec
 # per-heuristic simulator body is *traced* (not dispatched). Tests read
 # this to pin the single-jit contract — every (policy, dispatcher,
 # dynamics, network, scenario) tuple of a sweep must trace exactly once
-# inside one XLA program. Bounded to the most recent entries so
-# long-lived processes don't accumulate.
+# inside one XLA program, and a cached program (a repeated sweep) traces
+# nothing. Bounded to the most recent entries so long-lived processes
+# don't accumulate.
 _TRACE_LOG: list = []
 _TRACE_LOG_MAX = 256
 
 # Host spans on the profiler's clock (a no-op unless a profiler runs):
-# ``sweep.build`` (policy resolution and simulator construction, up to the
-# jit call), ``sweep.trace.<NAME>`` (one per heuristic body JAX traces),
-# and in ``run_sweep`` ``sweep.stack`` and ``sweep.reduce``. Each
+# ``sweep.build`` (input resolution, the program cache's key and lookup,
+# and simulator construction on a miss, up to the jit call),
+# ``sweep.trace.<NAME>`` (one per heuristic body JAX traces), and in
+# ``run_sweep`` ``sweep.stack`` and ``sweep.reduce``. Each
 # heuristic's device loop runs under ``jax.named_scope("sweep.<NAME>")``.
 _annotate = jax.profiler.TraceAnnotation
+
+# Jitted sweep programs kept by ``_sweep_program``, least recently used
+# dropped first.
+_SWEEP_CACHE_MAX = 32
 
 
 def _select_fns(names, use_pallas: bool, use_pallas_map: bool = False):
@@ -71,6 +85,107 @@ def _resolve_dispatcher(dispatcher, use_pallas_map: bool = False):
     return disp
 
 
+class _SystemKey:
+    """A system compared by value, for the sweep program's cache key.
+
+    Two keys are equal when the systems' ``eet``, ``p_dyn`` and
+    ``p_idle`` agree in shape, dtype and bytes, and their queue size,
+    fairness factor, sites and tiers agree: the program closes over
+    those as constants. ``system`` is the first such system seen, the
+    one a cache miss builds from.
+    """
+
+    __slots__ = ("system", "_value")
+
+    def __init__(self, system):
+        self.system = system
+        arrays = tuple(
+            (a.shape, a.dtype.str, a.tobytes())
+            for a in map(np.asarray, (system.eet, system.p_dyn,
+                                      system.p_idle)))
+        self._value = (arrays, system.queue_size,
+                       float(system.fairness_factor), system.sites,
+                       getattr(system, "tiers", None))
+
+    def __hash__(self):
+        return hash(self._value)
+
+    def __eq__(self, other):
+        return (isinstance(other, _SystemKey)
+                and self._value == other._value)
+
+
+@functools.lru_cache(maxsize=_SWEEP_CACHE_MAX)
+def _sweep_program(system_key, heuristic_names, pols, obs, disp, dyn, net,
+                   max_steps, use_pallas_phase1, use_pallas_map, mesh,
+                   labels):
+    """The jitted sweep over every heuristic, built once per static key.
+
+    ``use_pallas_phase1``/``use_pallas_map`` are already applied to
+    ``pols`` and ``disp``; they are keys only. ``labels`` is the
+    ``(scenario, dispatcher, dynamics, network)`` annotation of
+    ``_TRACE_LOG``. With a ``mesh`` the program is the jitted
+    ``shard_map`` of the sweep over its batch axis.
+    """
+    del use_pallas_phase1, use_pallas_map
+    system = system_key.system
+    sysarr = system.as_jax()
+    sims = [
+        engine.make_simulator(
+            fn, sysarr, queue_size=system.queue_size,
+            fairness_factor=float(system.fairness_factor),
+            max_steps=max_steps, observers=obs,
+            dispatcher=disp, site_of_machine=system.sites,
+            dynamics=dyn, network=net,
+            tier_of_site=getattr(system, "tiers", None),
+        )
+        for fn in pols
+    ]
+
+    def run_all(tr):
+        per_h = []
+        for name, sim in zip(heuristic_names, sims):
+            _TRACE_LOG.append((name, *labels))  # trace-time
+            with (_annotate(f"sweep.trace.{name}"),
+                  jax.named_scope(f"sweep.{name}")):
+                per_h.append(jax.vmap(sim)(tr))
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *per_h)
+
+    if mesh is None:
+        return jax.jit(run_all)
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed import sharding
+
+    # check_vma=False: the engine's while_loop carry starts from
+    # constants (now=0, empty queues) that become varying over the
+    # grid axis after one step, and the loop requires the input and
+    # output carry types to match. Marking the initial carry varying
+    # would thread this mesh's axis name into the engine. Each device
+    # simulates its own slice with no collective, so the check has
+    # nothing to verify here.
+    return jax.jit(jax.shard_map(
+        run_all, mesh=mesh,
+        in_specs=P(sharding.SWEEP_AXIS),
+        out_specs=P(None, sharding.SWEEP_AXIS),
+        check_vma=False,
+    ))
+
+
+def sweep_cache_info():
+    """``(hits, misses, maxsize, currsize)`` of the sweep program cache.
+
+    A hit is a ``simulate_sweep`` call that reused a jitted program (no
+    simulator built, nothing traced); a miss built one.
+    """
+    return _sweep_program.cache_info()
+
+
+def clear_sweep_cache() -> None:
+    """Drop every cached sweep program and zero the hit/miss counts."""
+    _sweep_program.cache_clear()
+
+
 def simulate_sweep(traces: Trace, system: SystemSpec, heuristic_names,
                    *, use_pallas_phase1: bool = False,
                    use_pallas_map: bool = False,
@@ -78,6 +193,19 @@ def simulate_sweep(traces: Trace, system: SystemSpec, heuristic_names,
                    observers=(), dispatcher=None, dynamics=None,
                    network=None, shard: bool = False):
     """Simulate a flat batch of traces under every heuristic, in one jit.
+
+    The jitted program is cached (up to 32, least recently used dropped)
+    on what it closes over statically: the resolved policies with
+    ``heuristic_names``, the resolved observers, dispatcher, dynamics and
+    network, the system by value (``eet``, ``p_dyn``, ``p_idle`` by
+    shape, dtype and bytes; queue size, fairness factor, sites, tiers),
+    ``max_steps``, the Pallas toggles, the mesh and the trace-log labels.
+    A repeated call with equal static inputs re-runs the compiled program
+    without tracing it; JAX's own per-shape cache inside the jit handles a
+    new trace shape. Resolved objects, not names, are the key, so
+    re-registering a name with ``overwrite=True`` takes effect.
+    :func:`sweep_cache_info` counts hits and misses,
+    :func:`clear_sweep_cache` empties the cache.
 
     Args:
       traces: a Trace whose leaves have one flat leading batch dim B
@@ -140,57 +268,24 @@ def simulate_sweep(traces: Trace, system: SystemSpec, heuristic_names,
         net = network_mod.resolve(network)
         net_label = (network if isinstance(network, str)
                      else getattr(net, "kind", type(net).__name__))
-        sysarr = system.as_jax()
-        sims = [
-            engine.make_simulator(
-                fn, sysarr, queue_size=system.queue_size,
-                fairness_factor=float(system.fairness_factor),
-                max_steps=max_steps, observers=obs,
-                dispatcher=disp, site_of_machine=system.sites,
-                dynamics=dyn, network=net,
-                tier_of_site=getattr(system, "tiers", None),
-            )
-            for fn in _select_fns(heuristic_names, use_pallas_phase1,
-                                  use_pallas_map)
-        ]
+        names = tuple(heuristic_names)
+        pols = tuple(_select_fns(names, use_pallas_phase1, use_pallas_map))
         mesh = None
         if shard:
             from repro.distributed import sharding
 
             mesh = sharding.sweep_mesh()
-
-    def run_all(tr):
-        per_h = []
-        for name, sim in zip(heuristic_names, sims):
-            _TRACE_LOG.append(
-                (name, trace_label, disp_label, dyn_label,
-                 net_label))  # trace-time
-            with (_annotate(f"sweep.trace.{name}"),
-                  jax.named_scope(f"sweep.{name}")):
-                per_h.append(jax.vmap(sim)(tr))
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *per_h)
+        program = _sweep_program(
+            _SystemKey(system), names, pols, obs, disp, dyn, net, max_steps,
+            use_pallas_phase1, use_pallas_map, mesh,
+            (trace_label, disp_label, dyn_label, net_label))
 
     if mesh is None:
-        out = jax.jit(run_all)(traces)
+        out = program(traces)
     else:
-        from jax.sharding import PartitionSpec as P
-
         B = traces.arrival.shape[0]
         padded = sharding.pad_batch(traces, mesh.devices.size)
-        # check_vma=False: the engine's while_loop carry starts from
-        # constants (now=0, empty queues) that become varying over the
-        # grid axis after one step, and the loop requires the input and
-        # output carry types to match. Marking the initial carry varying
-        # would thread this mesh's axis name into the engine. Each device
-        # simulates its own slice with no collective, so the check has
-        # nothing to verify here.
-        sharded = jax.jit(jax.shard_map(
-            run_all, mesh=mesh,
-            in_specs=P(sharding.SWEEP_AXIS),
-            out_specs=P(None, sharding.SWEEP_AXIS),
-            check_vma=False,
-        ))
-        out = jax.tree.map(lambda x: x[:, :B], sharded(padded))
+        out = jax.tree.map(lambda x: x[:, :B], program(padded))
     del _TRACE_LOG[:-_TRACE_LOG_MAX]
     return out
 
